@@ -1,7 +1,10 @@
 """Coded-serving benchmark: tokens/s + synthetic TTFT tails.
 
-Two halves, both against the real ``repro.serve`` engine on the
-8-virtual-device mesh (smoke config):
+Two halves, both against the real ``repro.serve`` engine (smoke
+config) on ``mesh.make_device_mesh`` over the devices of this process
+-- on the CPU the caller sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` -- or on no
+mesh when there is one device:
 
 * **Engine runs** -- drain the same request set through the
   continuous-batching engine three ways: coded prefill (expander d=2)
@@ -18,20 +21,15 @@ Two halves, both against the real ``repro.serve`` engine on the
 Inline acceptance (the paper's claim, in serving clothes): coded p99 <
 uncoded p99 under the Bernoulli model at d=2 -- one deadline + rare
 retries instead of waiting out the slowest device -- with p50 within
-the jitter of the single-replica latency. The subprocess exists
-because the virtual-device count must land in XLA_FLAGS before jax
-initialises; ``main`` (the ``benchmarks.run`` entry) spawns it and
-returns the report run.py writes to BENCH_serve.json.
+the jitter of the single-replica latency. Everything runs in the
+calling process; ``main`` (the ``benchmarks.run`` entry) returns the
+report run.py writes to BENCH_serve.json, naming the device it ran on.
 """
 
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 
-N_DEVICES = 8
 M_REPLICAS = 32
 
 
@@ -53,12 +51,13 @@ def _engine_run(cfg, params, mesh, requests, *, scheme: str, p: float,
     return {"summary": summary, "results": eng.results()}
 
 
-def worker(full: bool) -> None:
+def measure(full: bool) -> dict:
     import numpy as np
 
+    from benchmarks.train_step import device_info
     from repro.configs import CodingConfig, get_config
     from repro.dist import coded_train
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_device_mesh
     from repro.models import model as M
     from repro import serve as S
 
@@ -66,7 +65,7 @@ def worker(full: bool) -> None:
 
     # --- engine half: real device runs -------------------------------
     cfg = get_config("qwen1.5-4b").smoke_variant()
-    mesh = make_test_mesh((N_DEVICES // 2, 2))
+    mesh = make_device_mesh() if len(jax.devices()) > 1 else None
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     n_req = 24 if full else 12
     slots, max_len, new_tokens = 8, 48, 8
@@ -120,8 +119,8 @@ def worker(full: bool) -> None:
         if model == "bernoulli":
             coded_p99, uncoded_p99 = c_row["p99_ms"], u_row["p99_ms"]
 
-    report = {
-        "n_virtual_devices": N_DEVICES,
+    return {
+        "device": device_info(),
         "m_replicas_sim": M_REPLICAS,
         "rounds_sim": rounds,
         "requests": n_req,
@@ -134,26 +133,10 @@ def worker(full: bool) -> None:
             "coded_p99_lt_uncoded": bool(coded_p99 < uncoded_p99),
         },
     }
-    print("BENCH_SERVE_JSON:" + json.dumps(report))
 
 
 def main(fast: bool = True) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={N_DEVICES}")
-    cmd = [sys.executable, "-m", "benchmarks.serve_bench", "--worker"]
-    if not fast:
-        cmd.append("--full")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=1800,
-                          cwd=os.path.dirname(os.path.dirname(
-                              os.path.abspath(__file__))))
-    if proc.returncode != 0:
-        raise RuntimeError(f"serve_bench worker failed:\n{proc.stdout}"
-                           f"\n{proc.stderr}")
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_SERVE_JSON:")][-1]
-    report = json.loads(line.split(":", 1)[1])
+    report = measure(full=not fast)
     for name, s in report["engine"].items():
         ttft = (f", TTFT p50 {s['ttft_p50_ms']:.1f} ms "
                 f"p99 {s['ttft_p99_ms']:.1f} ms"
@@ -182,10 +165,6 @@ if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true")
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
-    if args.worker:
-        worker(args.full)
-    else:
-        print(json.dumps(main(fast=not args.full), indent=2))
+    print(json.dumps(main(fast=not args.full), indent=2))

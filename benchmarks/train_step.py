@@ -1,7 +1,9 @@
-"""Coded-vs-uncoded train-step benchmark on the 8-virtual-device mesh.
+"""Coded-vs-uncoded train-step benchmark on the devices of this process.
 
-Measures, for the real ``repro.dist`` runtime (smoke config, (4, 2)
-mesh of virtual CPU devices):
+Measures, for the real ``repro.dist`` runtime (smoke config, on
+``mesh.make_device_mesh`` over the devices the process sees -- on the
+CPU the caller sets ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+for the (4, 2) mesh of virtual devices):
 
 * per-step wall time (median over timed steps, compile excluded),
 * unique tokens/s (global batch x seq len / step time -- replicated
@@ -10,16 +12,17 @@ mesh of virtual CPU devices):
   (sample + cached O(m) optimal decode) and the batched
   ``decode_batch`` path, in microseconds.
 
-Nine rows: the replicated coded step (GSPMD combine), the
+Nine rows, at m = 4 coded machines unless a row says otherwise: the
+replicated coded step (GSPMD combine), the
 deduplicated coded step (each unique block once, weighted by
 v = A @ w -- the path that closes the replication-factor gap), the
 manual ``coded_allreduce`` collective, the uncoded baseline, the
 compression-composed dedup steps (int8 / sign / packed 1-bit sign
 through the fused quantized combine, with measured
 comm-bytes-per-step columns), and the streaming-vs-materialising
-manual pair at m = 8 machines (two per worker shard, so the
-``lax.scan`` streaming accumulator genuinely halves the live
-per-chunk gradients). Every row carries a ``memory`` column: the
+manual pair at m = 8 machines (two per worker shard on a 4-shard data
+axis, so the ``lax.scan`` streaming accumulator genuinely halves the
+live per-chunk gradients). Every row carries a ``memory`` column: the
 compiled step's XLA ``memory_analysis`` (argument/output/temp/program
 bytes) plus the peak host-visible live-buffer bytes sampled across
 the timed steps. Inline acceptance pins the dedup step strictly under
@@ -28,21 +31,26 @@ the materialising manual's; the comm-bytes acceptances (int8 <= 0.3x,
 sign_packed <= 0.05x float32) live in
 ``roofline_report.comm_report``.
 
-The measurement loop runs in a subprocess because the virtual-device
-count must land in XLA_FLAGS before jax initialises; ``main`` (the
-``benchmarks.run`` entry) spawns it and returns the parsed report,
-which run.py writes to BENCH_train.json.
+Everything runs in the calling process, which holds the devices;
+``main`` (the ``benchmarks.run`` entry) returns the report, which
+run.py writes to BENCH_train.json. Every report names the device it
+ran on.
 """
 
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 import time
 
-N_DEVICES = 8
+M_WORKERS = 4  # coded machines per row, independent of the device count
+
+
+def device_info() -> dict:
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
 
 
 def _measure_one(scheme: str, decoding: str, *, steps: int,
@@ -59,7 +67,7 @@ def _measure_one(scheme: str, decoding: str, *, steps: int,
     from repro.core import compress as compress_mod
     from repro.data.pipeline import CodedBatcher, SyntheticLM
     from repro.dist import coded_train, sharding as rules
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_device_mesh
     from repro.models import model as M
     from repro.optim import optimizers as opt_mod
 
@@ -67,12 +75,12 @@ def _measure_one(scheme: str, decoding: str, *, steps: int,
     codec = (None if compress == "none"
              else compress_mod.get_codec(compress))
     cfg = get_config("qwen1.5-4b").smoke_variant()
-    mesh = make_test_mesh((N_DEVICES // 2, 2))
+    mesh = make_device_mesh()
     # ``machines`` > the data-axis size gives each worker shard a
     # block of several machines -- the regime where the streaming
     # accumulator holds fewer live gradients than the materialised
     # manual combine.
-    m_workers = machines or mesh.shape["data"]
+    m_workers = machines or M_WORKERS
     coding = CodingConfig(scheme=scheme, replication=2, decoding=decoding,
                           straggler_p=0.2, seed=0)
     runtime = coded_train.CodingRuntime(coding, m_workers)
@@ -101,7 +109,7 @@ def _measure_one(scheme: str, decoding: str, *, steps: int,
             norm_scale=coded_train.dedup_norm_scale(assignment),
             compress=compress if codec else None)
     step_times, decode_times = [], []
-    with mesh:
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, pshard)
         # Shapes are static: shardings + jit once, outside the loop
         # (the same hoisting the async driver does).
@@ -203,10 +211,9 @@ def _measure_one(scheme: str, decoding: str, *, steps: int,
 
 def _measure_chaos(steps: int) -> dict:
     """Chaos row: the full elastic-fault-tolerance loop through the
-    real train driver, in-process (this worker already owns the 8
-    virtual devices). Kills one of the 4 coded machines a third of the
-    way in and reports detection latency, steps trained on the
-    degraded mask, the elastic re-assignment record, and the final
+    real train driver, in-process. Kills one of the 4 coded machines a
+    third of the way in and reports detection latency, steps trained on
+    the degraded mask, the elastic re-assignment record, and the final
     loss against the identical no-failure run -- straggler sampling
     off on both sides so injected chaos is the only difference."""
     from repro.launch import train as train_mod
@@ -214,7 +221,7 @@ def _measure_chaos(steps: int) -> dict:
     kill_step = max(2, steps // 3)
     base = ["--arch", "qwen1.5-4b", "--steps", str(steps),
             "--seq-len", "32", "--block-size", "2",
-            "--straggler-p", "0",
+            "--straggler-p", "0", "--machines", str(M_WORKERS),
             "--log-every", str(max(1, steps // 2))]
     clean = train_mod.main(base)
     t0 = time.perf_counter()
@@ -238,11 +245,11 @@ def _measure_chaos(steps: int) -> dict:
     }
 
 
-def worker(full: bool) -> None:
+def measure(full: bool) -> dict:
     steps = 24 if full else 8
     kw = dict(steps=steps, seq_len=64, block_size=4)
-    report = {
-        "n_virtual_devices": N_DEVICES,
+    return {
+        "device": device_info(),
         "steps_timed": steps,
         "runs": [
             _measure_one("expander", "optimal", path="replicated", **kw),
@@ -272,7 +279,6 @@ def worker(full: bool) -> None:
         # no-failure run, through the real driver
         "chaos": _measure_chaos(steps),
     }
-    print("BENCH_TRAIN_JSON:" + json.dumps(report))
 
 
 def find_run(runs, **want) -> dict:
@@ -281,22 +287,7 @@ def find_run(runs, **want) -> dict:
 
 
 def main(fast: bool = True) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={N_DEVICES}")
-    cmd = [sys.executable, "-m", "benchmarks.train_step", "--worker"]
-    if not fast:
-        cmd.append("--full")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=1800,
-                          cwd=os.path.dirname(os.path.dirname(
-                              os.path.abspath(__file__))))
-    if proc.returncode != 0:
-        raise RuntimeError(f"train_step worker failed:\n{proc.stdout}"
-                           f"\n{proc.stderr}")
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_TRAIN_JSON:")][-1]
-    report = json.loads(line.split(":", 1)[1])
+    report = measure(full=not fast)
     for run in report["runs"]:
         label = f"{run['scheme']}/{run['path']}/{run['collective']}"
         if run.get("compress", "none") != "none":
@@ -367,10 +358,6 @@ if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true")
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
-    if args.worker:
-        worker(args.full)
-    else:
-        print(json.dumps(main(fast=not args.full), indent=2))
+    print(json.dumps(main(fast=not args.full), indent=2))

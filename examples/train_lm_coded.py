@@ -1,5 +1,7 @@
 """End-to-end driver: coded training of a (reduced) assigned
-architecture on the virtual-device mesh, with live straggler sampling
+architecture over m = 4 coded machines on the devices the process sees
+(``--machines``; set ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+for a (4, 2) mesh of virtual CPU devices), with live straggler sampling
 and O(m) optimal decoding. Wraps repro.launch.train with its async
 pipeline defaults: deduplicated block execution (each unique block
 once, weighted by v = A @ w), lookahead-batched decoding, and
@@ -42,7 +44,7 @@ log lands in the summary's ``chaos`` object and, with
      "m_final": M, "generations": G}
 
 Try: ``PYTHONPATH=src python examples/train_lm_coded.py --steps 20 \
---straggler-p 0 --chaos "kill:1@5" --compress none``
+--machines 4 --straggler-p 0 --chaos "kill:1@5" --compress none``
 
     PYTHONPATH=src python examples/train_lm_coded.py [--arch ...]
 """
@@ -54,7 +56,7 @@ from repro.launch import train
 
 def main():
     argv = sys.argv[1:] or [
-        "--arch", "deepseek-moe-16b", "--steps", "40",
+        "--arch", "deepseek-moe-16b", "--steps", "40", "--machines", "4",
         "--seq-len", "48", "--block-size", "2", "--lr", "1e-3",
         "--straggler-p", "0.2", "--scheme", "expander",
         "--decoding", "optimal", "--replication", "2",

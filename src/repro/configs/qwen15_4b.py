@@ -1,5 +1,5 @@
 """qwen1.5-4b [dense]: 40L d_model=2560 20H (GQA kv=20) d_ff=6912
-vocab=151936, QKV bias. [hf:Qwen/Qwen1.5-0.5B family]"""
+vocab=151936, QKV bias. [hf:Qwen/Qwen1.5-4B config.json]"""
 
 from .base import ModelConfig
 
@@ -13,5 +13,5 @@ CONFIG = ModelConfig(
     d_ff=6912,
     vocab_size=151936,
     qkv_bias=True,
-    source="hf:Qwen/Qwen1.5-0.5B",
+    source="hf:Qwen/Qwen1.5-4B",
 )

@@ -133,10 +133,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax moved it to the top level
-    shard_map = jax.shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import CodingConfig, ModelConfig
@@ -456,8 +452,8 @@ def _coded_psum_allreduce(mesh, local_combine_fn, trees, w: jnp.ndarray):
         out = local_combine_fn(*local, w_local)
         return jax.tree.map(lambda x: jax.lax.psum(x, axes), out)
 
-    return shard_map(body, mesh=mesh, in_specs=(*in_specs, P(lead)),
-                     out_specs=jax.tree.map(lambda _: P(), trees[0]))(
+    return jax.shard_map(body, mesh=mesh, in_specs=(*in_specs, P(lead)),
+                         out_specs=jax.tree.map(lambda _: P(), trees[0]))(
         *trees, w)
 
 

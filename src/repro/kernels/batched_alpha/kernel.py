@@ -7,8 +7,10 @@ streaming sweep (same roofline shape as ``coded_combine``: ~3 FLOPs per
 4 bytes read, each alpha byte read exactly once).
 
 Grid: (trials // block_t,); each step owns a (block_t, n) VMEM strip and
-emits block_t per-trial errors. The scalar ``scale`` is broadcast to
-every step as a whole (tiny) block. The n axis is padded to the 128-lane
+emits block_t per-trial errors as a (block_t, 1) column -- the output
+block spans the whole (padded_trials, 1) array's lane dim, which keeps
+it legal for the TPU compiler. The scalar ``scale`` rides in SMEM. The
+n axis is padded to the 128-lane
 boundary with 1/scale so padding contributes exactly zero error; padded
 trailing trials are sliced off.
 """
@@ -20,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pick_block_t(trials: int, n: int) -> int:
@@ -53,17 +56,18 @@ def fused_error(alphas: jnp.ndarray, scale: jnp.ndarray, *,
     def body(a_ref, s_ref, o_ref):
         a = a_ref[...].astype(jnp.float32)      # (bt, n_pad)
         d = a * s_ref[0] - 1.0
-        o_ref[...] = (jnp.sum(d * d, axis=1) * inv_n).astype(o_ref.dtype)
+        o_ref[...] = jnp.sum(d * d, axis=1, keepdims=True) * inv_n
 
     out = pl.pallas_call(
         body,
         grid=(padded_trials // bt,),
         in_specs=[
             pl.BlockSpec((bt, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((bt,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((padded_trials,), jnp.float32),
+        out_specs=pl.BlockSpec((bt, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((padded_trials, 1), jnp.float32),
+        name="batched_alpha_fused_error",
         interpret=interpret,
     )(alphas, scale)
-    return out[:trials] if pad_t else out
+    return out[:trials, 0]

@@ -41,19 +41,11 @@ def fused_error(alphas, *, debias: bool = True) -> Tuple[np.ndarray, float]:
     scale = debias_scale(a) if debias else 1.0
     if _FORCE == "ref":
         return ref.fused_error(a, scale), scale
-    use_pallas = _FORCE == "pallas"
-    interpret = False
-    if use_pallas or _FORCE is None:
-        try:
-            import jax
+    import jax
 
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover
-            on_tpu = False
-        if use_pallas:
-            interpret = not on_tpu
-        else:
-            use_pallas = on_tpu
+    on_tpu = jax.default_backend() == "tpu"
+    use_pallas = _FORCE == "pallas" or on_tpu
+    interpret = not on_tpu
     if use_pallas:
         import jax.numpy as jnp
 

@@ -8,8 +8,11 @@ tiles while keeping the online-softmax state (m, l, acc) resident.
 Grid: (B, KVH, S // block_k). TPU iterates the last axis sequentially,
 so the (m, l, acc) VMEM scratch accumulates across the KV blocks of one
 (batch, kv-head) pair and is reset when the block index wraps to 0.
-K/V tiles are (block_k, Dh) VMEM blocks; the G = H/KVH query heads of
-the group stay resident as a (G, Dh) tile. ``lengths`` rides in SMEM via
+K/V tiles are (block_k, Dh) VMEM blocks: the (B, S, KVH, Dh) cache is
+viewed as (B, S, KVH * Dh), and kv-head h is lane block h of width Dh,
+so every block the TPU compiler sees is (block_k, Dh) with Dh a
+multiple of 128 (its (8, 128) rule). The G = H/KVH query heads of the
+group stay resident as a (G, Dh) tile. ``lengths`` rides in SMEM via
 scalar prefetch so the mask needs no extra HBM traffic.
 """
 
@@ -23,12 +26,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# float32 operands on the MXU: full float32 products, not one bf16 pass.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _decode_attn_kernel(lengths_ref,  # scalar prefetch: (B,) int32 SMEM
                         q_ref,        # (1, 1, G, Dh) VMEM
-                        k_ref,        # (1, block_k, 1, Dh) VMEM
-                        v_ref,        # (1, block_k, 1, Dh) VMEM
+                        k_ref,        # (block_k, Dh) VMEM (squeezed)
+                        v_ref,        # (block_k, Dh) VMEM (squeezed)
                         o_ref,        # (1, 1, G, Dh) VMEM
                         m_ref, l_ref, acc_ref,  # VMEM scratch
                         *, block_k: int, scale: float):
@@ -43,10 +48,12 @@ def _decode_attn_kernel(lengths_ref,  # scalar prefetch: (B,) int32 SMEM
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)            # (G, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (block_k, Dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (block_k, Dh)
+    k = k_ref[...].astype(jnp.float32)             # (block_k, Dh)
+    v = v_ref[...].astype(jnp.float32)             # (block_k, Dh)
 
-    scores = (q @ k.T) * scale                      # (G, block_k)
+    scores = jax.lax.dot_general(                   # (G, block_k) = q k^T
+        q, k, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32) * scale
     length = lengths_ref[b]
     positions = s * block_k + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 1)
@@ -59,7 +66,8 @@ def _decode_attn_kernel(lengths_ref,  # scalar prefetch: (B,) int32 SMEM
     correction = jnp.exp(m_prev - m_new)
     l_ref[...] = correction * l_ref[...] + jnp.sum(p, axis=-1,
                                                    keepdims=True)
-    acc_ref[...] = correction * acc_ref[...] + p @ v
+    acc_ref[...] = correction * acc_ref[...] + jax.lax.dot(
+        p, v, precision=_HIGHEST, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(s == num_s - 1)
@@ -85,6 +93,8 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if S % block_k:
         raise ValueError("S must be a multiple of block_k")
     qg = q.reshape(B, KVH, G, Dh)
+    k = k.reshape(B, S, KVH * Dh)
+    v = v.reshape(B, S, KVH * Dh)
 
     grid = (B, KVH, S // block_k)
     out = pl.pallas_call(
@@ -95,10 +105,10 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, Dh), lambda b, h, s, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, Dh),
-                             lambda b, h, s, *_: (b, s, h, 0)),
-                pl.BlockSpec((1, block_k, 1, Dh),
-                             lambda b, h, s, *_: (b, s, h, 0)),
+                pl.BlockSpec((None, block_k, Dh),
+                             lambda b, h, s, *_: (b, s, h)),
+                pl.BlockSpec((None, block_k, Dh),
+                             lambda b, h, s, *_: (b, s, h)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, Dh),
                                    lambda b, h, s, *_: (b, h, 0, 0)),
@@ -109,6 +119,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, Dh), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(B, H, Dh)

@@ -24,6 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# float32 operands on the MXU: full float32 products, not one bf16 pass.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _pick_block_r(rows: int, k: int) -> int:
     budget = 2 * 1024 * 1024 // (4 * max(k, 1))  # ~2 MiB strip
@@ -66,10 +69,10 @@ def gram_matvec(x: jnp.ndarray, v: jnp.ndarray, *,
 
         xb = x_ref[...]                              # (br, kp)
         y = jax.lax.dot_general(                     # (br, bv) = X_blk V^T
-            xb, v_ref[...], (((1,), (1,)), ((), ())),
+            xb, v_ref[...], (((1,), (1,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
         o_ref[...] += jax.lax.dot_general(           # (bv, kp) = Y^T X_blk
-            y, xb, (((0,), (0,)), ((), ())),
+            y, xb, (((0,), (0,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
 
     out = pl.pallas_call(
@@ -118,10 +121,10 @@ def gram_matvec_batch(x: jnp.ndarray, v: jnp.ndarray, *,
 
         xb = x_ref[0]                                # (br, kp)
         y = jax.lax.dot_general(                     # (br, 1) = X_blk v
-            xb, v_ref[0], (((1,), (1,)), ((), ())),
+            xb, v_ref[0], (((1,), (1,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
         o_ref[0] += jax.lax.dot_general(             # (1, kp) = y^T X_blk
-            y, xb, (((0,), (0,)), ((), ())),
+            y, xb, (((0,), (0,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
 
     out = pl.pallas_call(

@@ -20,19 +20,11 @@ def _dispatch():
     """-> ('ref', False) or ('pallas', interpret)."""
     if _FORCE == "ref":
         return "ref", False
-    use_pallas = _FORCE == "pallas"
-    interpret = False
-    if use_pallas or _FORCE is None:
-        try:
-            import jax
+    import jax
 
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover
-            on_tpu = False
-        if use_pallas:
-            interpret = not on_tpu
-        else:
-            use_pallas = on_tpu
+    on_tpu = jax.default_backend() == "tpu"
+    use_pallas = _FORCE == "pallas" or on_tpu
+    interpret = not on_tpu
     return ("pallas", interpret) if use_pallas else ("ref", False)
 
 

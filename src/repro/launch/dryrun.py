@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes, record memory/cost/collective analysis.
 
-MUST be run as a script / module (``python -m repro.launch.dryrun``):
-the XLA_FLAGS line above executes before any jax import, giving 512
-placeholder CPU devices for the 2x16x16 production mesh.
+MUST be run as a script / module (``python -m repro.launch.dryrun``) in
+a fresh process: ``main`` sets XLA_FLAGS before any device is used,
+giving 512 placeholder CPU devices for the 2x16x16 production mesh.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen1.5-4b --shape train_4k
@@ -15,6 +12,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -115,8 +113,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict]
-        cost = cost[0] if cost else {}
     stats = hlo_analysis.analyze(compiled.as_text())
     n_chips = mesh.devices.size
     model = rl_mod.model_flops(cfg, shape,
@@ -158,6 +154,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -1,8 +1,8 @@
-"""Production mesh definitions.
+"""Mesh definitions.
 
-``make_production_mesh`` is a function (not a module-level constant) so
-importing this module never touches jax device state; the dry-run sets
-XLA_FLAGS before any jax import to get 512 placeholder devices.
+Functions, not module-level constants, so importing this module never
+touches jax device state; the dry-run asks for 512 placeholder CPU
+devices in its ``main`` before any device is used.
 """
 
 from __future__ import annotations
@@ -11,12 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types; 0.4.x meshes are implicitly Auto.
-    if hasattr(jax.sharding, "AxisType"):  # pragma: no cover
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,6 +27,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over whatever devices exist (CPU tests)."""
     return _make_mesh(shape, axes)
+
+
+def make_device_mesh():
+    """(data, model) mesh over every device the process sees: model
+    parallelism 2 on an even count n > 1, so (n/2, 2); else (n, 1)."""
+    n_dev = len(jax.devices())
+    model_par = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
+    return make_test_mesh((n_dev // model_par, model_par))
 
 
 def num_coded_workers(mesh) -> int:

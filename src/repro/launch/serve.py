@@ -1,7 +1,3 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 """Serving driver: the CLI over the continuous-batching coded engine.
 
 Builds a ``repro.serve.ServeEngine`` -- admission queue, fixed-slot
@@ -13,6 +9,11 @@ summary line (tokens/s, synthetic TTFT p50/p99, retries).
 
   python -m repro.launch.serve --arch qwen1.5-4b --requests 12 \
       --scheme expander --straggler-p 0.2
+
+The mesh is built over the devices the process sees (none on one
+device); on the CPU a caller that wants several sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in the
+environment of the process it starts.
 
 ``--check`` re-serves the same requests through the sequential-
 batching reference loop and asserts bit-identical token streams (and,
@@ -31,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import CodingConfig, get_config
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_production_mesh
 from repro.models import model as M
 from repro import serve as S
 
@@ -94,7 +96,7 @@ def _static_main(args, cfg):
     dt = time.perf_counter() - t0
     gen = np.stack(out_tokens, axis=1)
     assert not np.isnan(np.asarray(logits)).any()
-    summary = {"path": "static", "arch": args.arch,
+    summary = {"path": "static", "arch": cfg.name,
                "requests": B, "new_tokens": int(gen.size),
                "tokens_per_s": gen.size / max(dt, 1e-9),
                "sample": gen[0][:12].tolist()}
@@ -102,7 +104,9 @@ def _static_main(args, cfg):
     return {"tokens": gen, "summary": summary}
 
 
-def main(argv=None) -> dict:
+def main(argv=None, *, cfg=None) -> dict:
+    """Run the driver on ``argv``. ``cfg`` (a ModelConfig) replaces
+    the registry config that ``--arch`` / ``--full-config`` select."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b")
     ap.add_argument("--requests", type=int, default=12)
@@ -140,9 +144,10 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if not args.full_config:
-        cfg = cfg.smoke_variant()
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if not args.full_config:
+            cfg = cfg.smoke_variant()
 
     # Validate the generation budget against the cache capacity (and
     # any config max_seq_len) BEFORE touching the device -- the old
@@ -152,6 +157,7 @@ def main(argv=None) -> dict:
                           args.max_len)
     except ValueError as e:
         ap.error(str(e))
+    enable_compile_cache()
 
     if cfg.arch_type in ("vlm", "audio"):
         return _static_main(args, cfg)
@@ -161,9 +167,7 @@ def main(argv=None) -> dict:
     elif args.no_mesh or len(jax.devices()) == 1:
         mesh = None
     else:
-        n_dev = len(jax.devices())
-        model_par = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
-        mesh = make_test_mesh((n_dev // model_par, model_par))
+        mesh = make_device_mesh()
 
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     reqs = _build_requests(args, cfg)
@@ -194,7 +198,7 @@ def main(argv=None) -> dict:
         assert check_passed, \
             "engine streams diverged from the sequential reference"
 
-    summary.update(path="engine", arch=args.arch, scheme=args.scheme,
+    summary.update(path="engine", arch=cfg.name, scheme=args.scheme,
                    m_replicas=args.replicas,
                    replication=args.replication,
                    straggler_model=args.straggler_model,

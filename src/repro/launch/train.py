@@ -1,15 +1,19 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    # Standalone CPU demo: 8 virtual devices -> mesh (data=4, model=2).
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 """End-to-end coded LM training driver.
 
 Runs REAL training (not a dry-run): synthetic LM corpus -> coded block
 partitioner -> shard_map/pjit coded train step with host-side straggler
-sampling + O(m) optimal decoding. On CPU it uses the reduced smoke
-configs and a (4, 2) mesh of virtual devices; on a TPU pod the same
-driver takes the full configs and the production mesh.
+sampling + O(m) optimal decoding. The mesh is built over the devices
+the process sees (``mesh.make_device_mesh``). On the CPU a caller that
+wants several devices sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in the
+environment of the process it starts. The driver uses the reduced
+smoke configs unless ``--full-config`` is given or ``main`` is passed
+a ``cfg``.
+
+``--machines M`` sets the number m of coded machines independently of
+the chips: the data axis carries the blocks of all m machines, so one
+chip can run the paper's m >> chips regime. The default 0 keeps
+m = the data-axis size.
 
 The loop is an async pipeline: shardings and the jitted step are built
 once per *generation* (shapes are static until an elastic
@@ -60,6 +64,7 @@ re-assignment lands in the structured failure-event log (summary
 
 import argparse
 import json
+import os
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -74,15 +79,24 @@ from repro.core import step_weights as sw
 from repro.data.pipeline import CodedBatcher, SyntheticLM
 from repro.dist import chaos as chaos_mod
 from repro.dist import coded_train, failures, sharding as rules
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_device_mesh, make_production_mesh
 from repro.models import model as M
 from repro.optim import optimizers as opt_mod
 
 
-def main(argv=None) -> dict:
+def main(argv=None, *, cfg=None, mesh=None) -> dict:
+    """Run the driver on ``argv``. ``cfg`` (a ModelConfig) replaces
+    the registry config that ``--arch`` / ``--full-config`` select;
+    ``mesh`` (with a "data" and a "model" axis) replaces the mesh over
+    every device."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b")
     ap.add_argument("--steps", type=int, default=20)
+    ap.add_argument("--machines", type=int, default=0,
+                    help="coded machines m (0: the mesh's data-axis "
+                         "size); the data axis carries all m machines' "
+                         "blocks, so m may exceed the chip count")
     ap.add_argument("--scheme", default="expander",
                     choices=("expander", "frc", "uncoded", "cyclic_mds",
                              "bibd", "random_regular"))
@@ -198,44 +212,58 @@ def main(argv=None) -> dict:
     elif args.event_log:
         ap.error("--event-log only applies under --chaos")
 
-    cfg = get_config(args.arch)
-    if not args.full_config:
-        cfg = cfg.smoke_variant()
-
-    if args.production_mesh:
-        mesh = make_production_mesh()
-    else:
-        n_dev = len(jax.devices())
-        model_par = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
-        mesh = make_test_mesh((n_dev // model_par, model_par))
-
+    if args.machines < 0:
+        ap.error("--machines must be >= 0")
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if not args.full_config:
+            cfg = cfg.smoke_variant()
     dedup = args.collective == "gspmd" and args.dedup is not False
-
-    m_workers = mesh.shape["data"] * mesh.shape.get("pod", 1)
     coding = CodingConfig(
         scheme=args.scheme, replication=args.replication,
         decoding=args.decoding, straggler_model=args.straggler_model,
         straggler_p=args.straggler_p, seed=args.seed)
-    # Chaos mode swaps the runtime's mask source from sampled to
-    # observed: masks are pushed per step from the heartbeat monitor
-    # instead of drawn from the straggler model.
-    injector = monitor = surv = None
+
     adaptive = None if args.adaptive == "none" else args.adaptive
-    if args.chaos:
-        schedule = chaos_mod.parse_chaos_spec(args.chaos, m_workers)
-        injector = chaos_mod.ChaosInjector(schedule, m_workers,
-                                           seed=args.seed)
-        monitor = failures.HeartbeatMonitor(
-            m_workers, deadline=args.heartbeat_deadline,
-            dead_after=args.dead_after)
-        surv = failures.SurvivorMap(m_workers)
-        runtime = coded_train.CodingRuntime(
-            coding, m_workers,
-            mask_source=sw.ObservedMaskSource(m_workers),
-            adaptive=adaptive)
-    else:
-        runtime = coded_train.CodingRuntime(coding, m_workers,
-                                            adaptive=adaptive)
+
+    def coded_runtime(m):
+        """The coding runtime over m machines (and, under --chaos, its
+        failure injector, heartbeat monitor and survivor map). A
+        (scheme, m, d) the code cannot take fails here, naming the flag
+        that sets m."""
+        chaos = (None, None, None)
+        kw = {"adaptive": adaptive}
+        if args.chaos:
+            # Chaos mode swaps the runtime's mask source from sampled
+            # to observed: masks are pushed per step from the heartbeat
+            # monitor instead of drawn from the straggler model.
+            schedule = chaos_mod.parse_chaos_spec(args.chaos, m)
+            chaos = (chaos_mod.ChaosInjector(schedule, m, seed=args.seed),
+                     failures.HeartbeatMonitor(
+                         m, deadline=args.heartbeat_deadline,
+                         dead_after=args.dead_after),
+                     failures.SurvivorMap(m))
+            kw["mask_source"] = sw.ObservedMaskSource(m)
+        try:
+            return (coded_train.CodingRuntime(coding, m, **kw), *chaos)
+        except (ValueError, RuntimeError) as e:
+            ap.error(f"--machines: no {args.scheme} code with m={m} "
+                     f"machines and --replication {args.replication} "
+                     f"({e})")
+
+    m_workers = args.machines
+    if m_workers:
+        # Known before the device is touched: validate the code first.
+        runtime, injector, monitor, surv = coded_runtime(m_workers)
+    enable_compile_cache()
+
+    if mesh is None:
+        mesh = (make_production_mesh() if args.production_mesh
+                else make_device_mesh())
+
+    if not m_workers:
+        m_workers = mesh.shape["data"] * mesh.shape.get("pod", 1)
+        runtime, injector, monitor, surv = coded_runtime(m_workers)
     lookahead = max(1, args.lookahead)
     log_every = args.log_every or max(1, args.steps // 10)
 
@@ -338,7 +366,7 @@ def main(argv=None) -> dict:
     fail_at = int(os.environ.get("REPRO_FAIL_BATCH_AT", "-1"))
 
     pool = ThreadPoolExecutor(max_workers=1)
-    with mesh:
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, pshard)
         opt_state = jax.device_put(opt_state, oshard)
 

@@ -18,6 +18,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -60,6 +61,35 @@ def test_train_driver_smoke_async_dedup_pipeline():
     # the summary line means the full coded path (batcher -> decode ->
     # sharded step) ran and learned
     assert summary["last_loss"] < summary["first_loss"] + 1.0
+
+
+ONE_DEVICE = {"XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+
+
+def test_train_driver_machines_independent_of_devices():
+    """--machines decouples the coded machine count from the chips:
+    m = 12 machines (n = 12 blocks) on a one-device mesh, the one-chip
+    regime of the paper's m >> chips."""
+    summary = _run_driver("--machines", "12", "--steps", "3",
+                          "--block-size", "1", "--log-every", "1",
+                          env_extra=ONE_DEVICE)
+    assert summary["steps"] == 3
+    assert summary["m_workers"] == 12
+    assert summary["path"] == "dedup"
+    assert len(summary["losses"]) == 3
+    assert np.isfinite(summary["losses"]).all()
+
+
+@pytest.mark.parametrize("extra", [("--machines", "1"), ()],
+                         ids=["explicit", "from_one_device_mesh"])
+def test_train_driver_invalid_code_names_machines(extra):
+    """A code the scheme cannot build (expander d=2 over m=1: a graph
+    with no edges) fails before training with an error that names the
+    flag setting m -- whether m was given or came from the mesh."""
+    proc = _driver_proc(*extra, env_extra=ONE_DEVICE, check=False)
+    assert proc.returncode != 0
+    assert "--machines" in proc.stderr
+    assert "m=1" in proc.stderr
 
 
 def test_train_driver_smoke_manual_collective():
