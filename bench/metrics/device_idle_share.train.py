@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over the chips, in percent."""
+
+
+def read(ctx):
+    if ctx["kind"] != "train" or ctx["trace"] is None:
+        return None
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
