@@ -18,6 +18,7 @@ from typing import Dict, Iterator, Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core.assignment import Assignment
 
 
@@ -31,21 +32,23 @@ class SyntheticLM:
     seed: int = 0
 
     def batch(self, global_batch: int, step: int) -> Dict[str, np.ndarray]:
-        rng = np.random.default_rng(self.seed + 7919 * step)
-        V = self.vocab_size
-        ranks = np.arange(1, V + 1)
-        probs = 1.0 / ranks
-        probs /= probs.sum()
-        toks = rng.choice(V, size=(global_batch, self.seq_len + 1),
-                          p=probs)
-        # copy motif: second half repeats the first half for 1/4 of rows
-        k = global_batch // 4
-        half = (self.seq_len + 1) // 2
-        toks[:k, half:2 * half] = toks[:k, :half]
-        return {
-            "tokens": toks[:, :-1].astype(np.int32),
-            "labels": toks[:, 1:].astype(np.int32),
-        }
+        with spans.span("data.batch", step=step):
+            rng = np.random.default_rng(self.seed + 7919 * step)
+            V = self.vocab_size
+            ranks = np.arange(1, V + 1)
+            probs = 1.0 / ranks
+            probs /= probs.sum()
+            toks = rng.choice(V, size=(global_batch, self.seq_len + 1),
+                              p=probs)
+            # copy motif: second half repeats the first half for 1/4 of
+            # rows
+            k = global_batch // 4
+            half = (self.seq_len + 1) // 2
+            toks[:k, half:2 * half] = toks[:k, :half]
+            return {
+                "tokens": toks[:, :-1].astype(np.int32),
+                "labels": toks[:, 1:].astype(np.int32),
+            }
 
 
 @dataclasses.dataclass
@@ -86,15 +89,16 @@ class CodedBatcher:
                    ) -> Dict[str, np.ndarray]:
         n = self.assignment.n
         out = {}
-        for k, v in batch.items():
-            gb = v.shape[0]
-            if gb % n:
-                raise ValueError(f"global batch {gb} not divisible by "
-                                 f"n={n} blocks")
-            bs = gb // n
-            blocks = v.reshape((n, bs) + v.shape[1:])
-            blocks = blocks[self.rho]          # rho shuffle
-            out[k] = blocks[self.block_ids]    # (m, load, bs, ...)
+        with spans.span("data.blocks", blocks=n):
+            for k, v in batch.items():
+                gb = v.shape[0]
+                if gb % n:
+                    raise ValueError(f"global batch {gb} not divisible "
+                                     f"by n={n} blocks")
+                bs = gb // n
+                blocks = v.reshape((n, bs) + v.shape[1:])
+                blocks = blocks[self.rho]          # rho shuffle
+                out[k] = blocks[self.block_ids]    # (m, load, bs, ...)
         out["block_weight"] = self.block_mask  # (m, load)
         return out
 
@@ -111,13 +115,14 @@ class CodedBatcher:
         """
         n = self.assignment.n
         out = {}
-        for k, v in batch.items():
-            gb = v.shape[0]
-            if gb % n:
-                raise ValueError(f"global batch {gb} not divisible by "
-                                 f"n={n} blocks")
-            bs = gb // n
-            out[k] = v.reshape((n, bs) + v.shape[1:])[self.rho]
+        with spans.span("data.blocks", blocks=n):
+            for k, v in batch.items():
+                gb = v.shape[0]
+                if gb % n:
+                    raise ValueError(f"global batch {gb} not divisible "
+                                     f"by n={n} blocks")
+                bs = gb // n
+                out[k] = v.reshape((n, bs) + v.shape[1:])[self.rho]
         return out
 
 

@@ -135,6 +135,7 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
+from repro import spans
 from repro.configs.base import CodingConfig, ModelConfig
 import repro.core.compress as compress_mod
 import repro.core.step_weights as sw
@@ -259,6 +260,7 @@ def _per_machine_values_and_grads(params, batch, cfg, norm=None):
     if norm is None:
         norm = batch["labels"].size
 
+    @jax.named_scope("coded.loss")
     def machine_loss(p, mb, bw_j):
         flat = {k: x.reshape((-1,) + x.shape[2:])
                 for k, x in mb.items()}
@@ -270,6 +272,24 @@ def _per_machine_values_and_grads(params, batch, cfg, norm=None):
     return jax.vmap(
         lambda mb, bw_j: jax.value_and_grad(machine_loss)(
             params, mb, bw_j))(data, bw)
+
+
+def _finish(optimizer: opt_mod.Optimizer, params, opt_state, grads, w, *,
+            dedup: bool = False, aw=None, **metrics):
+    """The tail every train step shares: the optimizer update, then the
+    gradient's norm and the debias divisor ``alpha_bar`` (mean(v) on
+    the dedup path, (colsum(A)/n) . w via ``aw`` on the replicated one,
+    none without it) beside the step's other on-device ``metrics``."""
+    with jax.named_scope("coded.optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        params = opt_mod.apply_updates(params, updates)
+    with jax.named_scope("coded.metrics"):
+        metrics["grad_norm"] = opt_mod.global_norm(grads)
+        if dedup:
+            metrics["alpha_bar"] = w.mean()
+        elif aw is not None:
+            metrics["alpha_bar"] = jnp.dot(aw, w)
+    return params, opt_state, metrics
 
 
 def make_train_step(cfg: ModelConfig, optimizer: opt_mod.Optimizer,
@@ -328,6 +348,7 @@ def make_train_step(cfg: ModelConfig, optimizer: opt_mod.Optimizer,
                 labels = batch["labels"]
                 norm = labels.size * norm_scale
 
+                @jax.named_scope("coded.loss")
                 def block_loss(p, blk):
                     per_seq = M.train_loss(p, blk, cfg,
                                            per_example=True)
@@ -340,26 +361,21 @@ def make_train_step(cfg: ModelConfig, optimizer: opt_mod.Optimizer,
                 losses, grads = _per_machine_values_and_grads(
                     params, batch, cfg)
             loss = (w * losses).sum()
-            combined, new_resid = compress_combine_tree(
-                grads, comp_state["residual"], w, codec,
-                error_feedback=error_feedback)
+            with jax.named_scope("coded.combine"):
+                combined, new_resid = compress_combine_tree(
+                    grads, comp_state["residual"], w, codec,
+                    error_feedback=error_feedback)
             rows = w.shape[0]
             comm = compress_mod.comm_bytes_per_step(
                 codec, int(rows), params)
-            updates, opt_state = optimizer.update(combined, opt_state,
-                                                  params)
-            params = opt_mod.apply_updates(params, updates)
-            metrics = {"loss": loss,
-                       "grad_norm": opt_mod.global_norm(combined),
-                       "comm_bytes": jnp.asarray(comm, jnp.float32)}
-            if dedup:
-                metrics["alpha_bar"] = w.mean()
-            elif aw is not None:
-                metrics["alpha_bar"] = jnp.dot(aw, w)
+            params, opt_state, metrics = _finish(
+                optimizer, params, opt_state, combined, w, dedup=dedup,
+                aw=aw, loss=loss, comm_bytes=jnp.asarray(comm, jnp.float32))
             return params, opt_state, {"residual": new_resid}, metrics
 
         return compressed_step
 
+    @jax.named_scope("coded.loss")
     def loss_fn(p, b, wv):
         if dedup:
             return coded_loss_fn_dedup(p, b, wv, cfg,
@@ -402,15 +418,8 @@ def make_train_step(cfg: ModelConfig, optimizer: opt_mod.Optimizer,
                 body, (zeros, jnp.zeros((), jnp.float32)), micro)
             grads = jax.tree.map(lambda g: g / nm, gsum)
             loss = lsum / nm
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = opt_mod.apply_updates(params, updates)
-        metrics = {"loss": loss,
-                   "grad_norm": opt_mod.global_norm(grads)}
-        if dedup:
-            metrics["alpha_bar"] = w.mean()
-        elif aw is not None:
-            metrics["alpha_bar"] = jnp.dot(aw, w)
-        return params, opt_state, metrics
+        return _finish(optimizer, params, opt_state, grads, w, dedup=dedup,
+                       aw=aw, loss=loss)
 
     return step
 
@@ -639,16 +648,9 @@ def make_manual_collective_train_step(cfg: ModelConfig,
     if streaming_chunk is not None and int(streaming_chunk) < 1:
         raise ValueError("streaming_chunk must be >= 1")
 
-    def _finish(params, opt_state, loss, grads, w, extra=None):
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = opt_mod.apply_updates(params, updates)
-        metrics = {"loss": loss,
-                   "grad_norm": opt_mod.global_norm(grads)}
-        if extra:
-            metrics.update(extra)
-        if aw is not None:
-            metrics["alpha_bar"] = jnp.dot(aw, w)
-        return params, opt_state, metrics
+    def finish(params, opt_state, loss, grads, w, **extra):
+        return _finish(optimizer, params, opt_state, grads, w, aw=aw,
+                       loss=loss, **extra)
 
     if streaming_chunk is not None:
         chunk = int(streaming_chunk)
@@ -681,15 +683,16 @@ def make_manual_collective_train_step(cfg: ModelConfig,
                 cb, w_c = xs_t[0], xs_t[1]
                 losses, grads = _per_machine_values_and_grads(
                     params, cb, cfg, norm=norm)
-                if codec is None:
-                    contrib = coded_allreduce(grads, w_c, mesh)
-                    new_r = None
-                else:
-                    q_t, s_t, new_r, shapes = _quantize_rows(
-                        grads, xs_t[2], codec, error_feedback)
-                    contrib = _compressed_allreduce(
-                        q_t, s_t, w_c, codec, shapes, mesh)
-                g_acc = jax.tree.map(jnp.add, g_acc, contrib)
+                with jax.named_scope("coded.combine"):
+                    if codec is None:
+                        contrib = coded_allreduce(grads, w_c, mesh)
+                        new_r = None
+                    else:
+                        q_t, s_t, new_r, shapes = _quantize_rows(
+                            grads, xs_t[2], codec, error_feedback)
+                        contrib = _compressed_allreduce(
+                            q_t, s_t, w_c, codec, shapes, mesh)
+                    g_acc = jax.tree.map(jnp.add, g_acc, contrib)
                 l_acc = l_acc + (w_c * losses).sum()
                 return (g_acc, l_acc), new_r
 
@@ -710,10 +713,9 @@ def make_manual_collective_train_step(cfg: ModelConfig,
                     params, batch, w, comp_state["residual"])
                 comm = compress_mod.comm_bytes_per_step(
                     codec, int(w.shape[0]), params)
-                params, opt_state, metrics = _finish(
+                params, opt_state, metrics = finish(
                     params, opt_state, loss, grads, w,
-                    extra={"comm_bytes": jnp.asarray(comm,
-                                                     jnp.float32)})
+                    comm_bytes=jnp.asarray(comm, jnp.float32))
                 return params, opt_state, {"residual": new_resid}, \
                     metrics
 
@@ -721,7 +723,7 @@ def make_manual_collective_train_step(cfg: ModelConfig,
 
         def streaming_step(params, opt_state, batch, w):
             grads, loss, _ = _scan_combine(params, batch, w, None)
-            return _finish(params, opt_state, loss, grads, w)
+            return finish(params, opt_state, loss, grads, w)
 
         return streaming_step
 
@@ -730,15 +732,16 @@ def make_manual_collective_train_step(cfg: ModelConfig,
             losses, grads = _per_machine_values_and_grads(
                 params, batch, cfg)
             loss = (w * losses).sum()
-            q_tree, s_tree, new_resid, shapes = _quantize_rows(
-                grads, comp_state["residual"], codec, error_feedback)
-            combined = _compressed_allreduce(q_tree, s_tree, w, codec,
-                                             shapes, mesh)
+            with jax.named_scope("coded.combine"):
+                q_tree, s_tree, new_resid, shapes = _quantize_rows(
+                    grads, comp_state["residual"], codec, error_feedback)
+                combined = _compressed_allreduce(q_tree, s_tree, w, codec,
+                                                 shapes, mesh)
             comm = compress_mod.comm_bytes_per_step(
                 codec, int(w.shape[0]), params)
-            params, opt_state, metrics = _finish(
+            params, opt_state, metrics = finish(
                 params, opt_state, loss, combined, w,
-                extra={"comm_bytes": jnp.asarray(comm, jnp.float32)})
+                comm_bytes=jnp.asarray(comm, jnp.float32))
             return params, opt_state, {"residual": new_resid}, metrics
 
         return compressed_step
@@ -746,9 +749,10 @@ def make_manual_collective_train_step(cfg: ModelConfig,
     def step(params, opt_state, batch, w):
         losses, grads = _per_machine_values_and_grads(params, batch,
                                                       cfg)
-        grads = coded_allreduce(grads, w, mesh)   # (m, ...) -> combine
+        with jax.named_scope("coded.combine"):
+            grads = coded_allreduce(grads, w, mesh)   # (m, ...) -> combine
         loss = (w * losses).sum()
-        return _finish(params, opt_state, loss, grads, w)
+        return finish(params, opt_state, loss, grads, w)
 
     return step
 
@@ -1021,16 +1025,22 @@ class CodingRuntime:
 
     def step_weights(self) -> Tuple[np.ndarray, np.ndarray]:
         """One round from the mask source: returns (w (m,) float32,
-        alive (m,) bool)."""
-        alive = self.mask_source.next_mask()
-        self.steps_sampled += 1
-        if self.policy is not None:
-            decision = self._decide()
-            w = self.weights_for(alive, method=decision.method,
-                                 p=decision.p)
-            self.estimator.observe(alive)
-            return w, alive
-        return self.weights_for(alive), alive
+        alive (m,) bool). Recorded as a ``coding.step_weights`` span of
+        that round, with ``novel`` 1 where the memo missed."""
+        calls = self.decode_calls
+        with spans.span("coding.step_weights",
+                        step=self.steps_sampled) as s:
+            alive = self.mask_source.next_mask()
+            self.steps_sampled += 1
+            if self.policy is not None:
+                decision = self._decide()
+                w = self.weights_for(alive, method=decision.method,
+                                     p=decision.p)
+                self.estimator.observe(alive)
+            else:
+                w = self.weights_for(alive)
+            s.set(novel=self.decode_calls - calls)
+        return w, alive
 
     def suggested_lookahead(self) -> int:
         """The policy's current prefetch-horizon suggestion (>= 1);
@@ -1070,9 +1080,22 @@ class CodingRuntime:
         round's decision may pick a different decoder, so there is no
         single-method batch to dispatch; bit-identity with the
         per-step loop is by construction.
+
+        Recorded as a ``coding.lookahead`` span tagged with the chunk's
+        first round, with ``rounds`` (the horizon) and ``novel`` (the
+        decodes the memo did not spare).
         """
         if horizon < 1:
             raise ValueError("horizon must be >= 1")
+        calls = self.decode_calls
+        with spans.span("coding.lookahead", step=self.steps_sampled,
+                        rounds=horizon) as s:
+            out = self._sample_and_decode(horizon)
+            s.set(novel=self.decode_calls - calls)
+        return out
+
+    def _sample_and_decode(self, horizon: int
+                           ) -> Tuple[np.ndarray, np.ndarray]:
         alive = np.stack(
             [self.mask_source.next_mask() for _ in range(horizon)])
         self.steps_sampled += horizon
@@ -1137,6 +1160,7 @@ class LookaheadPrefetcher:
         self.pool = pool
         self.horizon = horizon
         self.remaining = total_steps
+        self._round = runtime.steps_sampled   # the round next() hands out
         self._chunk = None
         self._cursor = 0
         self._future = self._submit()
@@ -1149,13 +1173,17 @@ class LookaheadPrefetcher:
         return self.pool.submit(self.runtime.weights_lookahead, k)
 
     def next(self) -> Tuple[np.ndarray, np.ndarray]:
-        """The next round's (w (m,) float32, alive (m,) bool)."""
-        if self._chunk is None or self._cursor == len(self._chunk[0]):
-            if self._future is None:
-                raise RuntimeError("lookahead stream exhausted")
-            self._chunk = self._future.result()
-            self._cursor = 0
-            self._future = self._submit()   # prefetch the next chunk
+        """The next round's (w (m,) float32, alive (m,) bool). Recorded
+        as a ``coding.wait`` span of that round: the caller's wait for
+        the chunk the worker thread decodes."""
+        with spans.span("coding.wait", step=self._round):
+            if self._chunk is None or self._cursor == len(self._chunk[0]):
+                if self._future is None:
+                    raise RuntimeError("lookahead stream exhausted")
+                self._chunk = self._future.result()
+                self._cursor = 0
+                self._future = self._submit()   # prefetch the next chunk
+        self._round += 1
         W, alive = self._chunk
         t = self._cursor
         self._cursor += 1

@@ -45,6 +45,15 @@ opt_state so resumes stay bit-identical. ``--fsdp`` shards params and
 Adam moments over the worker axes (``rules.fsdp_specs``) instead of
 replicating them.
 
+Every layer of the loop is timed by ``repro.spans``: the data layer
+(``data.batch``, ``data.blocks``) and the coding layer
+(``coding.lookahead``, ``coding.wait``, ``coding.step_weights``) from
+inside, and the loop's own phases here (``train.batch_wait``,
+``train.dispatch``, ``train.sync``, ``train.checkpoint``,
+``train.reassign``), each step also marked by a profiler
+``StepTraceAnnotation``. The summary's ``spans`` table gives each
+name's count, total_s, mean_ms and max_ms over the run.
+
 ``--chaos <spec>`` flips the straggler masks from *sampled* to
 *observed*: a seeded ``dist.chaos.ChaosInjector`` simulates per-step
 per-machine completion timestamps (kills, delays, rack failures,
@@ -63,6 +72,7 @@ re-assignment lands in the structured failure-event log (summary
 """
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -72,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.checkpoint import checkpoint as ckpt
 from repro.configs import CodingConfig, get_config
 from repro.core import compress as compress_mod
@@ -214,6 +225,7 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
 
     if args.machines < 0:
         ap.error("--machines must be >= 0")
+    spans.clear()   # the summary's spans table covers this run alone
     if cfg is None:
         cfg = get_config(args.arch)
         if not args.full_config:
@@ -376,7 +388,9 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
         reassignments = []         # chaos: elastic re-draw records
         generation = 0
         step = start
-        rebuild_started = None
+        # An elastic re-assignment's span runs from the re-draw of the
+        # code to the rebuilt generation's jitted step.
+        rebuild = contextlib.ExitStack()
         t0 = time.time()
 
         def flush_metrics():
@@ -469,10 +483,10 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
                         in_shardings=(pshard, oshard, bshard, repl),
                         out_shardings=(pshard, oshard, None),
                         donate_argnums=(0, 1))
-                if rebuild_started is not None:
+                if generation:
+                    rebuild.close()
                     reassignments[-1]["rebuild_s"] = round(
-                        time.time() - rebuild_started, 3)
-                    rebuild_started = None
+                        reassign_span.ms / 1e3, 3)
 
                 # Straggler sampling + batched decode run on the same
                 # worker thread as batch building, one chunk ahead of
@@ -488,52 +502,62 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
                 reassign_dead = None
 
                 while step < args.steps:
-                    if pending is not None:
-                        # Re-raises any worker-thread exception here,
-                        # on the main loop, with its traceback.
-                        batch_np = pending.result()
-                    if step + 1 < args.steps:
-                        # Double buffer: the worker thread builds
-                        # step+1's batch while the device runs step's
-                        # compute.
-                        pending = pool.submit(host_batch, step + 1)
-                    batch = {k: jax.device_put(jnp.asarray(v),
-                                               bshard[k])
-                             for k, v in batch_np.items()}
-                    if args.chaos:
-                        times = injector.completion_times(step)
-                        observed = monitor.observe(step, times)
-                        runtime.mask_source.push(
-                            surv.localize(observed))
-                        w, alive = runtime.step_weights()
-                    else:
-                        w, alive = lookahead_w.next()
-                    wv = runtime.block_weights(w) if dedup else w
-                    wv = jax.device_put(jnp.asarray(wv, jnp.float32),
-                                        repl)
-                    if compress:
-                        params, opt_state, comp_state, metrics = \
-                            step_fn(params, opt_state, comp_state,
-                                    batch, wv)
-                    else:
-                        params, opt_state, metrics = step_fn(
-                            params, opt_state, batch, wv)
-                    metrics_hist.append(metrics)
-                    if step % log_every == 0 or \
-                            step == args.steps - 1:
-                        # The only host<->device syncs in the loop:
-                        # one bulk fetch per log interval keeps the
-                        # metrics buffer bounded by log_every on
-                        # arbitrarily long runs.
-                        flush_metrics()
-                        print(f"step {step:4d} loss "
-                              f"{losses[-1]:.4f} stragglers "
-                              f"{int((~alive).sum())}/{runtime.m} "
-                              f"({time.time() - t0:.1f}s)")
-                    if args.ckpt_dir and args.ckpt_every and \
-                            (step + 1) % args.ckpt_every == 0 and \
-                            step + 1 < args.steps:
-                        save_ckpt(step + 1)
+                    with jax.profiler.StepTraceAnnotation(
+                            "train", step_num=step):
+                        if pending is not None:
+                            # Re-raises any worker-thread exception
+                            # here, on the main loop, with its
+                            # traceback.
+                            with spans.span("train.batch_wait",
+                                            step=step):
+                                batch_np = pending.result()
+                        if step + 1 < args.steps:
+                            # Double buffer: the worker thread builds
+                            # step+1's batch while the device runs
+                            # step's compute.
+                            pending = pool.submit(host_batch, step + 1)
+                        if args.chaos:
+                            times = injector.completion_times(step)
+                            observed = monitor.observe(step, times)
+                            runtime.mask_source.push(
+                                surv.localize(observed))
+                            w, alive = runtime.step_weights()
+                        else:
+                            w, alive = lookahead_w.next()
+                        with spans.span("train.dispatch", step=step):
+                            batch = {k: jax.device_put(jnp.asarray(v),
+                                                       bshard[k])
+                                     for k, v in batch_np.items()}
+                            wv = runtime.block_weights(w) if dedup else w
+                            wv = jax.device_put(
+                                jnp.asarray(wv, jnp.float32), repl)
+                            if compress:
+                                params, opt_state, comp_state, \
+                                    metrics = step_fn(
+                                        params, opt_state, comp_state,
+                                        batch, wv)
+                            else:
+                                params, opt_state, metrics = step_fn(
+                                    params, opt_state, batch, wv)
+                        metrics_hist.append(metrics)
+                        if step % log_every == 0 or \
+                                step == args.steps - 1:
+                            # The only host<->device syncs in the loop:
+                            # one bulk fetch per log interval keeps the
+                            # metrics buffer bounded by log_every on
+                            # arbitrarily long runs.
+                            with spans.span("train.sync", step=step):
+                                flush_metrics()
+                            print(f"step {step:4d} loss "
+                                  f"{losses[-1]:.4f} stragglers "
+                                  f"{int((~alive).sum())}/{runtime.m} "
+                                  f"({time.time() - t0:.1f}s)")
+                        if args.ckpt_dir and args.ckpt_every and \
+                                (step + 1) % args.ckpt_every == 0 and \
+                                step + 1 < args.steps:
+                            with spans.span("train.checkpoint",
+                                            step=step):
+                                save_ckpt(step + 1)
                     step += 1
                     if args.chaos:
                         new_events = monitor.drain_events()
@@ -560,7 +584,8 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
                             f"step {step}: all machines dead, cannot "
                             "re-assign")
                     flush_metrics()
-                    rebuild_started = time.time()
+                    reassign_span = rebuild.enter_context(
+                        spans.span("train.reassign", step=step))
                     local = [int(np.where(surv.survivors == d)[0][0])
                              for d in reassign_dead]
                     surv.remove(reassign_dead)
@@ -591,10 +616,13 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
                           f"(generation {generation}, d="
                           f"{runtime.coding.replication})")
 
-            flush_metrics()
+            with spans.span("train.sync", step=step):
+                flush_metrics()
             if args.ckpt_dir:
-                save_ckpt(args.steps)
+                with spans.span("train.checkpoint", step=step):
+                    save_ckpt(args.steps)
         finally:
+            rebuild.close()
             # Pipeline hardening: whatever killed the loop (injected
             # batch failure, jit error, KeyboardInterrupt), cancel the
             # queued worker tasks and join the in-flight one so the
@@ -641,7 +669,8 @@ def main(argv=None, *, cfg=None, mesh=None) -> dict:
                "comm_bytes_per_step": comm_bytes,
                "comm_bytes_per_step_float32": comm_bytes_f32,
                "decode_calls": runtime.decode_calls,
-               "chaos": chaos_summary}
+               "chaos": chaos_summary,
+               "spans": spans.totals()}
     if runtime.policy is not None:
         est = runtime.estimator.estimate()
         summary["adaptive"] = {

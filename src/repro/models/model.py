@@ -328,11 +328,13 @@ def forward_hidden(params, tokens, cfg: ModelConfig, *,
     """Final-norm hidden states (B, S_total, D)."""
     window = window if window is not None else cfg.sliding_window
     dt = jnp.dtype(cfg.dtype)
-    x = embed(params["embed"], tokens).astype(dt)
+    with jax.named_scope("model.embed"):
+        x = embed(params["embed"], tokens).astype(dt)
     if prefix is not None:
         x = jnp.concatenate([prefix.astype(dt), x], axis=1)
     enc = encode(params, src, cfg) if src is not None else None
-    x = _backbone(params, x, cfg, window=window, src=enc)
+    with jax.named_scope("model.layers"):
+        x = _backbone(params, x, cfg, window=window, src=enc)
     return rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
 
@@ -349,7 +351,8 @@ def forward(params, tokens, cfg: ModelConfig, *,
     """
     x = forward_hidden(params, tokens, cfg, prefix=prefix, src=src,
                        window=window)
-    return _lm_head(params, x, cfg)
+    with jax.named_scope("model.head"):
+        return _lm_head(params, x, cfg)
 
 
 def train_loss(params, batch, cfg: ModelConfig, *,
@@ -359,28 +362,30 @@ def train_loss(params, batch, cfg: ModelConfig, *,
     f = sum_i f_i; the caller normalises by the global token count.
     ``per_example`` returns per-sequence sums (B,) for the coded
     per-block combine."""
-    logits = forward(params, batch["tokens"], cfg,
-                     prefix=batch.get("prefix"), src=batch.get("src"))
-    labels = batch["labels"]
-    if batch.get("prefix") is not None:
-        logits = logits[:, batch["prefix"].shape[1]:]
-    # mask padded vocab entries out of the softmax (iota mask instead of
-    # a scatter: cheaper under a vocab-sharded layout)
-    vocab = cfg.padded_vocab()
-    if vocab != cfg.vocab_size:
-        vmask = jnp.arange(vocab) < cfg.vocab_size
-        logits = jnp.where(vmask, logits, -1e30)
-    # ll = logits[label] - logsumexp(logits): avoids a second (B, S, V)
-    # log-softmax intermediate.
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[..., None],
-                                 axis=-1)[..., 0]
-    ll = picked - lse
-    mask = (labels >= 0).astype(jnp.float32)
-    loss = -(ll * mask)
-    if per_example:
-        return loss.sum(axis=-1)
-    return loss.sum()
+    x = forward_hidden(params, batch["tokens"], cfg,
+                       prefix=batch.get("prefix"), src=batch.get("src"))
+    with jax.named_scope("model.head"):
+        logits = _lm_head(params, x, cfg)
+        labels = batch["labels"]
+        if batch.get("prefix") is not None:
+            logits = logits[:, batch["prefix"].shape[1]:]
+        # mask padded vocab entries out of the softmax (iota mask
+        # instead of a scatter: cheaper under a vocab-sharded layout)
+        vocab = cfg.padded_vocab()
+        if vocab != cfg.vocab_size:
+            vmask = jnp.arange(vocab) < cfg.vocab_size
+            logits = jnp.where(vmask, logits, -1e30)
+        # ll = logits[label] - logsumexp(logits): avoids a second
+        # (B, S, V) log-softmax intermediate.
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, labels[..., None],
+                                     axis=-1)[..., 0]
+        ll = picked - lse
+        mask = (labels >= 0).astype(jnp.float32)
+        loss = -(ll * mask)
+        if per_example:
+            return loss.sum(axis=-1)
+        return loss.sum()
 
 
 # ---------------------------------------------------------------------------

@@ -174,6 +174,10 @@ def test_train_driver_chaos_kill_reassigns_and_converges(tmp_path):
     assert len(chaos["reassignments"]) == 1
     re = chaos["reassignments"][0]
     assert re["dead"] == [1] and re["survivors"] == [0, 2, 3]
+    # The re-assignment's rebuild time is its train.reassign span.
+    assert summary["spans"]["train.reassign"]["count"] == 1
+    assert re["rebuild_s"] == round(
+        summary["spans"]["train.reassign"]["total_s"], 3)
     kinds = [e["kind"] for e in chaos["events"]]
     assert kinds == ["straggle", "dead", "reassign"]
     # Pre-kill steps see identical inputs (same seed, no stragglers):
