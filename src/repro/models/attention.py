@@ -1,11 +1,17 @@
-"""Attention: GQA with RoPE, blockwise (flash-style) training/prefill
-path, and a cached single-token decode path backed by the flash-decode
+"""Attention: GQA with RoPE, a full-sequence training/prefill path,
+and a cached single-token decode path backed by the flash-decode
 Pallas kernel.
 
-The training path is a pure-jnp online-softmax over KV blocks driven by
-``lax.scan`` so the HLO stays small and the (S x S) score matrix is
-never materialised -- mandatory for prefill_32k. Causal and
-sliding-window masks are applied per (q-block, kv-block) tile.
+The full-sequence path takes the fused flash-attention Pallas kernel
+(``kernels/flash_attention``, forward and backward) on TPU for
+self-attention of whole sequences whose shapes it tiles
+(``uses_flash_kernel``). Every other case -- sliding windows,
+cross-attention, a query block at an offset, lengths or head sizes
+that are not multiples of 128, and every backend but the TPU -- runs
+``blockwise_attention``: a pure-jnp online softmax over KV blocks
+driven by ``lax.scan``, so the HLO stays small and the (S x S) score
+matrix is never materialised. Causal and sliding-window masks are
+applied per (q-block, kv-block) tile.
 """
 
 from __future__ import annotations
@@ -17,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import ops as decode_ops
+from repro.kernels.flash_attention import ops as flash_ops
 from .layers import init_linear, linear, apply_rope
 
 NEG_INF = -1e30
@@ -78,7 +85,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
     # Keep tiles in the input dtype (bf16 on TPU) and accumulate the
     # dots in fp32 via preferred_element_type: halves the HBM/ICI bytes
-    # of every attention tile vs f32 operands (EXPERIMENTS.md #Perf).
+    # of every attention tile vs f32 operands.
     qf = q.reshape(B, nq, block_q, KVH, G, Dh)
     kf = k.reshape(B, nk, block_k, KVH, Dh)
     vf = v.reshape(B, nk, block_k, KVH, Dh)
@@ -122,6 +129,16 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     return out.astype(q.dtype)
 
 
+def uses_flash_kernel(q_shape, k_shape, *, cross: bool,
+                      window: Optional[int], q_offset: int = 0) -> bool:
+    """Whether full-sequence attention over q (B, Sq, H, Dh) and k
+    (B, Sk, KVH, Dh) takes the flash-attention kernel: where the
+    kernel is enabled (on TPU), for self-attention of a whole sequence
+    (no window, no query offset) with shapes it tiles."""
+    return (flash_ops.enabled() and not cross and window is None
+            and q_offset == 0 and flash_ops.supports(q_shape, k_shape))
+
+
 def attention_forward(p, x, *, n_heads: int, n_kv_heads: int,
                       head_dim: int, rope_theta: float,
                       causal: bool = True,
@@ -146,9 +163,13 @@ def attention_forward(p, x, *, n_heads: int, n_kv_heads: int,
         k = apply_rope(k, jnp.broadcast_to(
             jnp.arange(src.shape[1])[None, :], (B, src.shape[1])),
             rope_theta)
-    out = blockwise_attention(q, k, v, causal=causal and kv is None,
-                              window=window, block_q=block_q,
-                              block_k=block_k)
+    if uses_flash_kernel(q.shape, k.shape, cross=kv is not None,
+                         window=window):
+        out = flash_ops.flash_attention(q, k, v, causal=causal)
+    else:
+        out = blockwise_attention(q, k, v, causal=causal and kv is None,
+                                  window=window, block_q=block_q,
+                                  block_k=block_k)
     return linear(p["wo"], out.reshape(B, S, n_heads * head_dim))
 
 

@@ -10,6 +10,7 @@ from repro.kernels.batched_alpha import kernel as ba_k, ops as ba_ops, \
     ref as ba_r
 from repro.kernels.coded_combine import kernel as cc_k, ref as cc_r
 from repro.kernels.decode_attention import kernel as da_k, ref as da_r
+from repro.kernels.flash_attention import kernel as fa_k, ref as fa_r
 from repro.kernels.rmsnorm import kernel as rn_k, ops as rn_ops, \
     ref as rn_r
 from repro.kernels.spectral_matvec import kernel as sm_k, ops as sm_ops, \
@@ -88,6 +89,109 @@ def test_decode_attention_respects_lengths():
     out2 = da_k.decode_attention(q, k2, v2, lengths, block_k=32,
                                  interpret=True)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
+
+
+def _flash_inputs(H, KVH, S, dtype, B=1, Dh=128):
+    rng = np.random.default_rng(S * 10 + H * KVH)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rng.normal(size=(B, S, H, Dh)), dt)
+    k = jnp.asarray(rng.normal(size=(B, S, KVH, Dh)), dt)
+    v = jnp.asarray(rng.normal(size=(B, S, KVH, Dh)), dt)
+    return q, k, v
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+FLASH_CASES = pytest.mark.parametrize("H,KVH", [(4, 4), (4, 1)],
+                                      ids=["mha", "gqa4"])
+FLASH_SHAPES = pytest.mark.parametrize("S", [128, 256, 1024])
+FLASH_MASKS = pytest.mark.parametrize("causal", [True, False],
+                                      ids=["causal", "full"])
+FLASH_DTYPES = pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+
+
+@FLASH_CASES
+@FLASH_SHAPES
+@FLASH_MASKS
+@FLASH_DTYPES
+def test_flash_attention_forward_matches_oracle(H, KVH, S, causal, dtype):
+    q, k, v = _flash_inputs(H, KVH, S, dtype)
+    out = fa_k.flash_attention(q, k, v, causal, True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref = fa_r.flash_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                               causal)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), **_tol(dtype))
+
+
+@FLASH_CASES
+@FLASH_SHAPES
+@FLASH_MASKS
+@FLASH_DTYPES
+def test_flash_attention_grads_match_oracle(H, KVH, S, causal, dtype):
+    """dq, dk, dv of the custom VJP's two backward kernels against
+    autodiff of the float32 oracle."""
+    q, k, v = _flash_inputs(H, KVH, S, dtype)
+    w = jnp.asarray(np.random.default_rng(S).normal(size=q.shape),
+                    jnp.float32)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    got = jax.grad(loss(lambda *a: fa_k.flash_attention(*a, causal, True)),
+                   (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: fa_r.flash_attention(*a, causal)),
+                    (0, 1, 2))(*(x.astype(jnp.float32) for x in (q, k, v)))
+    tol = 2e-2 if dtype == "bfloat16" else 1e-5
+    for g, x, r in zip(got, (q, k, v), want):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        assert _rel(g, r) < tol
+
+
+@pytest.mark.parametrize("blocks", [(1024, 256, 1024), (1024, 512, 128),
+                                    (256, 128, 128), (128, 128, 128)])
+def test_flash_attention_tilings_agree(blocks, monkeypatch):
+    """Every (block, forward tile, backward tile) split of 1024 causal
+    tokens gives the oracle's output and gradients: one grid step of
+    tiles, 4 x 4 grid steps of 2 x 2 tiles (whole grid steps above the
+    diagonal skipped), 8 x 8 grid steps of one tile."""
+    monkeypatch.setattr(fa_k, "block_sizes", lambda S: blocks)
+    jax.clear_caches()
+    q, k, v = _flash_inputs(4, 2, 1024, "float32")
+    w = jnp.asarray(np.random.default_rng(7).normal(size=q.shape),
+                    jnp.float32)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * w)
+
+    out, got = jax.value_and_grad(
+        loss(lambda *a: fa_k.flash_attention(*a, True, True)),
+        (0, 1, 2))(q, k, v)
+    ref, want = jax.value_and_grad(
+        loss(lambda *a: fa_r.flash_attention(*a, True)), (0, 1, 2))(q, k, v)
+    assert abs(float(out - ref)) <= 1e-5 * abs(float(ref))
+    for g, r in zip(got, want):
+        assert _rel(g, r) < 1e-5
+    jax.clear_caches()
+
+
+@FLASH_CASES
+def test_flash_attention_masked_keys_cannot_change_output(H, KVH):
+    """Under the causal mask, keys and values after position t reach no
+    query at or before t: in a tile that is masked element by element
+    nor in one that is skipped."""
+    q, k, v = _flash_inputs(H, KVH, 1024, "float32")
+    t = 300
+    out = fa_k.flash_attention(q, k, v, True, True)
+    k2 = k.at[:, t + 1:].set(999.0)
+    v2 = v.at[:, t + 1:].set(-999.0)
+    out2 = fa_k.flash_attention(q, k2, v2, True, True)
+    np.testing.assert_array_equal(np.asarray(out[:, :t + 1]),
+                                  np.asarray(out2[:, :t + 1]))
+    assert float(jnp.abs(out[:, t + 1:] - out2[:, t + 1:]).max()) > 1.0
 
 
 @pytest.mark.parametrize("n,D", [(8, 1000), (24, 4096), (3, 130),

@@ -7,8 +7,9 @@ tiling, casts Mosaic cannot lower, VMEM overruns. Interpret-mode tests
 (tests/test_kernels.py) cannot see any of that.
 
 The widths are the ones the system runs at: Qwen1.5-4B's d_model 2560
-and d_ff 6912, its 20 KV heads of 128, 12 coded machines, and the
-paper-scale decoder's n = 2184 blocks (m = 6552, d = 6).
+and d_ff 6912, its 20 KV heads of 128, 12 coded machines, the
+training cell's 12 sequences of 1024 tokens, and the paper-scale
+decoder's n = 2184 blocks (m = 6552, d = 6).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and
@@ -24,6 +25,7 @@ import pytest
 from repro.kernels.batched_alpha import kernel as ba_k
 from repro.kernels.coded_combine import kernel as cc_k
 from repro.kernels.decode_attention import kernel as da_k
+from repro.kernels.flash_attention import kernel as fa_k
 from repro.kernels.rmsnorm import kernel as rn_k
 from repro.kernels.spectral_matvec import kernel as sm_k
 
@@ -49,6 +51,13 @@ def one_chip():
             yield jax.sharding.SingleDeviceSharding(topo.devices[0])
         finally:
             jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@jax.jit
+def _flash_fwd_bwd(q, k, v, do):
+    """The causal flash-attention forward and both backward kernels."""
+    o, vjp = jax.vjp(lambda *a: fa_k.flash_attention(*a, True), q, k, v)
+    return o, vjp(do)
 
 
 def _compile(fn, sharding, *shapes, **static):
@@ -79,6 +88,10 @@ KERNELS = {
         da_k.decode_attention, sh, ((8, 20, 128), "bfloat16"),
         ((8, 4096, 20, 128), "bfloat16"), ((8, 4096, 20, 128), "bfloat16"),
         ((8,), "int32")),
+    # The training cell's attention: 12 sequences of 1024 tokens, 20
+    # heads of 128, in the model's (B, S, H, Dh) layout.
+    "flash_attention": lambda sh: _compile(
+        _flash_fwd_bwd, sh, *[((12, 1024, 20, 128), "bfloat16")] * 4),
     "batched_alpha": lambda sh: _compile(
         ba_k.fused_error, sh, ((1000, 2184), "float32"), ((), "float32")),
 }
