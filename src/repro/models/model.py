@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.xent_grad import ops as xent_ops
 from . import attention as attn
 
 # Activation-sharding hook (set by the distributed launcher): the
@@ -355,34 +356,97 @@ def forward(params, tokens, cfg: ModelConfig, *,
         return _lm_head(params, x, cfg)
 
 
+def uses_xent_kernel(logits_shape) -> bool:
+    """Whether the head's cross-entropy backward takes the
+    ``kernels/xent_grad`` kernel: where the kernel is enabled (on TPU),
+    for logits (T, V) it tiles, at the default matmul precision. There
+    the head's dots already round their float32 operands to bfloat16,
+    the rounding of the kernel's bfloat16 gradient; under a higher
+    precision they are exact, and autodiff keeps them so."""
+    return (xent_ops.enabled() and xent_ops.supports(logits_shape)
+            and jax.config.jax_default_matmul_precision in (None, "default"))
+
+
+def _xent(logits, labels, vocab_size: int):
+    """(per-token NLL masked to real labels, the logits with padded ids
+    masked, their log-sum-exp) of float32 logits (..., V)."""
+    # mask padded vocab entries out of the softmax (iota mask
+    # instead of a scatter: cheaper under a vocab-sharded layout)
+    vocab = logits.shape[-1]
+    if vocab != vocab_size:
+        vmask = jnp.arange(vocab) < vocab_size
+        logits = jnp.where(vmask, logits, -1e30)
+    # ll = logits[label] - logsumexp(logits): avoids a second
+    # (B, S, V) log-softmax intermediate.
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, labels[..., None],
+                                 axis=-1)[..., 0]
+    ll = picked - lse
+    mask = (labels >= 0).astype(jnp.float32)
+    return -(ll * mask), logits, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def head_nll(x, w, labels, vocab_size: int):
+    """Per-token NLL (T,) of the head ``x (T, D) @ w (D, V)``, masked
+    to labels >= 0, computed as ``_lm_head`` and ``_xent`` compute it.
+    The backward computes the softmax gradient once, in bfloat16
+    (``kernels/xent_grad``), and feeds it to two bfloat16 matmuls with
+    float32 accumulation, dx and dw."""
+    return _head_nll_fwd(x, w, labels, vocab_size)[0]
+
+
+def _head_nll_fwd(x, w, labels, vocab_size):
+    nll, logits, lse = _xent(x.astype(jnp.float32) @ w.astype(jnp.float32),
+                             labels, vocab_size)
+    return nll, (x, w, labels, logits, lse)
+
+
+def _head_nll_bwd(vocab_size, res, g):
+    x, w, labels, logits, lse = res
+    d = xent_ops.xent_grad(logits, lse, labels, g * (labels >= 0),
+                           vocab_size=vocab_size)
+    bf = jnp.bfloat16
+    dx = jax.lax.dot_general(d, w.astype(bf), (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dw = jax.lax.dot_general(x.astype(bf), d, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    # Both matmuls before the layers' backward. Left free, the compiler
+    # fuses dw into the optimizer's update at the step's end, and the
+    # bf16 gradient (T x V) stays live across the whole backward scan.
+    dx, dw = jax.lax.optimization_barrier((dx, dw))
+    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+
+head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
+
+
 def train_loss(params, batch, cfg: ModelConfig, *,
                per_example: bool = False) -> jnp.ndarray:
     """Summed next-token cross entropy over real (non-pad) label
     positions. Sum (not mean) so per-block losses add like the paper's
     f = sum_i f_i; the caller normalises by the global token count.
     ``per_example`` returns per-sequence sums (B,) for the coded
-    per-block combine."""
+    per-block combine. Where ``uses_xent_kernel`` says so, the head
+    and its cross entropy run as ``head_nll``."""
     x = forward_hidden(params, batch["tokens"], cfg,
                        prefix=batch.get("prefix"), src=batch.get("src"))
     with jax.named_scope("model.head"):
-        logits = _lm_head(params, x, cfg)
         labels = batch["labels"]
-        if batch.get("prefix") is not None:
-            logits = logits[:, batch["prefix"].shape[1]:]
-        # mask padded vocab entries out of the softmax (iota mask
-        # instead of a scatter: cheaper under a vocab-sharded layout)
-        vocab = cfg.padded_vocab()
-        if vocab != cfg.vocab_size:
-            vmask = jnp.arange(vocab) < cfg.vocab_size
-            logits = jnp.where(vmask, logits, -1e30)
-        # ll = logits[label] - logsumexp(logits): avoids a second
-        # (B, S, V) log-softmax intermediate.
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, labels[..., None],
-                                     axis=-1)[..., 0]
-        ll = picked - lse
-        mask = (labels >= 0).astype(jnp.float32)
-        loss = -(ll * mask)
+        prefix = batch.get("prefix")
+        if uses_xent_kernel((labels.size, cfg.padded_vocab())):
+            if prefix is not None:
+                x = x[:, prefix.shape[1]:]
+            w = (params["embed"]["table"].T if cfg.tie_embeddings
+                 else params["lm_head"]["w"])
+            loss = head_nll(x.reshape(-1, x.shape[-1]), w,
+                            labels.reshape(-1),
+                            cfg.vocab_size).reshape(labels.shape)
+        else:
+            logits = _lm_head(params, x, cfg)
+            if prefix is not None:
+                logits = logits[:, prefix.shape[1]:]
+            loss = _xent(logits, labels, cfg.vocab_size)[0]
         if per_example:
             return loss.sum(axis=-1)
         return loss.sum()

@@ -15,6 +15,7 @@ from repro.kernels.rmsnorm import kernel as rn_k, ops as rn_ops, \
     ref as rn_r
 from repro.kernels.spectral_matvec import kernel as sm_k, ops as sm_ops, \
     ref as sm_r
+from repro.kernels.xent_grad import kernel as xg_k, ref as xg_r
 
 RNG = np.random.default_rng(0)
 
@@ -192,6 +193,95 @@ def test_flash_attention_masked_keys_cannot_change_output(H, KVH):
     np.testing.assert_array_equal(np.asarray(out[:, :t + 1]),
                                   np.asarray(out2[:, :t + 1]))
     assert float(jnp.abs(out[:, t + 1:] - out2[:, t + 1:]).max()) > 1.0
+
+
+def _xent_inputs(T, V, vocab_size, seed=0):
+    """Logits with the padded ids masked as the model masks them, their
+    log-sum-exp, labels with some rows ignored (-1), a scale per row."""
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(size=(T, V)).astype(np.float32) * 3
+    logits[:, vocab_size:] = -1e30
+    lse = jax.nn.logsumexp(jnp.asarray(logits), axis=-1)
+    labels = rng.integers(0, vocab_size, T).astype(np.int32)
+    labels[::5] = -1
+    scale = rng.normal(size=T).astype(np.float32)
+    return jnp.asarray(logits), lse, jnp.asarray(labels), jnp.asarray(scale)
+
+
+XENT_SHAPES = pytest.mark.parametrize("T,V,vocab_size", [
+    (37, 512, 500),        # odd rows, padded ids
+    (8, 256, 256),         # no padding
+    (24, 38144, 37984),    # the training cell's padded vocabulary
+    (40, 1024, 1000),      # rows in more than one block (small budget)
+])
+
+
+@XENT_SHAPES
+def test_xent_grad_kernel_within_one_bf16_ulp_of_oracle(T, V, vocab_size,
+                                                        monkeypatch):
+    if T == 40:   # 16-row blocks of 1024 columns: three row blocks
+        monkeypatch.setattr(xg_k, "VMEM_BUDGET", 16 * 1024 * 12)
+        assert xg_k.block_sizes(T, V, xg_k.VMEM_BUDGET) == (16, V)
+    args = _xent_inputs(T, V, vocab_size)
+    got = xg_k.xent_grad(*args, 0, vocab_size, True)
+    want = np.asarray(xg_r.xent_grad(*args, 0, vocab_size))
+    assert got.dtype == jnp.bfloat16 and got.shape == (T, V)
+    got = np.asarray(got.astype(jnp.float32))
+    mag = np.abs(want)
+    ulp = np.where(mag > 0, 2.0 ** (np.floor(np.log2(np.maximum(mag, 1e-38)))
+                                    - 7), 0.0)
+    assert np.all(np.abs(got - want) <= ulp)
+
+
+@XENT_SHAPES
+def test_xent_grad_zeroes_padded_ids_and_ignored_rows(T, V, vocab_size):
+    logits, lse, labels, scale = _xent_inputs(T, V, vocab_size)
+    # Even where the padded logits are not masked, nothing reaches them.
+    logits = logits.at[:, vocab_size:].set(5.0)
+    got = np.asarray(xg_k.xent_grad(logits, lse, labels, scale, 0,
+                                    vocab_size, True).astype(jnp.float32))
+    assert np.all(got[:, vocab_size:] == 0)
+    assert np.all(got[np.asarray(labels) < 0] == 0)
+    live = np.asarray(labels) >= 0
+    assert np.all(np.abs(got[live][:, :vocab_size]).sum(-1) > 0)
+
+
+def test_xent_grad_applies_the_row_scale():
+    """Doubling a row's scale doubles its gradient exactly; a zero
+    scale gives zeros; the one-hot term is negative at the label."""
+    logits, lse, labels, scale = _xent_inputs(37, 512, 500)
+    run = lambda s: np.asarray(xg_k.xent_grad(  # noqa: E731
+        logits, lse, labels, s, 0, 500, True).astype(jnp.float32))
+    base = run(jnp.ones_like(scale))
+    np.testing.assert_array_equal(run(2 * jnp.ones_like(scale)), 2 * base)
+    assert np.all(run(jnp.zeros_like(scale)) == 0)
+    rows = np.flatnonzero(np.asarray(labels) >= 0)
+    at_label = base[rows, np.asarray(labels)[rows]]
+    assert np.all(at_label < 0) and np.all(base[rows].sum(-1) < 1e-2)
+
+
+def test_xent_grad_column_offset_is_a_vocabulary_slice():
+    """A device holding columns from ``offset`` on (a vocabulary-sharded
+    head) computes exactly that slice of the whole gradient."""
+    args = _xent_inputs(24, 1024, 1000)
+    whole = xg_k.xent_grad(*args, 0, 1000, True)
+    logits, rest = args[0], args[1:]
+    for off in (0, 512):
+        part = xg_k.xent_grad(logits[:, off:off + 512], *rest, off, 1000,
+                              True)
+        np.testing.assert_array_equal(np.asarray(part),
+                                      np.asarray(whole[:, off:off + 512]))
+
+
+@pytest.mark.parametrize("T,V", [(12288, 38144), (37, 512), (4096, 256000),
+                                 (8, 128)])
+def test_xent_grad_blocks_fit_the_vmem_budget(T, V):
+    tm, tv = xg_k.block_sizes(T, V, xg_k.VMEM_BUDGET)
+    assert tv % 128 == 0 and tv <= V
+    assert tm == T or tm % 16 == 0
+    assert 2 * tm * tv * (4 + 2) <= xg_k.VMEM_BUDGET
+    if 16 * V * 12 <= xg_k.VMEM_BUDGET:
+        assert tv == V     # whole rows where they fit
 
 
 @pytest.mark.parametrize("n,D", [(8, 1000), (24, 4096), (3, 130),
@@ -491,3 +581,62 @@ def test_coded_combine_tree():
         out["a"], (tree["a"] * w[:, None]).sum(0), rtol=1e-6)
     np.testing.assert_allclose(out["b"], 3.5 * jnp.ones((2, 2)),
                                rtol=1e-6)
+
+
+def test_xent_grad_under_a_mesh_matches_one_device():
+    """Under (4, 1), (2, 2) and (1, 4) meshes of virtual CPU devices the
+    kernel runs in ``shard_map`` over rows and, where the ``model`` axis
+    splits it, the vocabulary: the result is the one-device result bit
+    for bit, and the head's gradients in ``train_loss`` agree with the
+    one-device gradients to 1e-4 (where a split sum moves a float32
+    value across a bfloat16 rounding boundary, an element of the
+    head's gradient differs by one bfloat16 unit). Subprocess: the
+    test process keeps one CPU device."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_config
+from repro.dist import sharding as rules
+from repro.kernels.xent_grad import ops
+from repro.launch.mesh import make_test_mesh
+from repro.models import model as M
+ops._FORCE = "pallas"
+rng = np.random.default_rng(0)
+T, V, vs = 48, 1024, 1000
+lg = jnp.asarray(rng.normal(size=(T, V)), jnp.float32)
+lse = jax.nn.logsumexp(lg[:, :vs], axis=-1)
+lab = jnp.asarray(rng.integers(-1, vs, T), jnp.int32)
+s = jnp.asarray(rng.normal(size=T), jnp.float32)
+run = jax.jit(lambda *a: ops.xent_grad(*a, vocab_size=vs))
+one = np.asarray(run(lg, lse, lab, s))
+cfg = get_config("qwen1.5-4b").smoke_variant()
+params = M.init_params(cfg, jax.random.PRNGKey(0))
+tok = jax.random.randint(jax.random.PRNGKey(1), (4, 24), 0, cfg.vocab_size)
+batch = {"tokens": tok, "labels": tok}
+grad = lambda p: jax.grad(lambda q: M.train_loss(q, batch, cfg))(p)
+g1 = jax.jit(grad)(params)
+for shape in [(4, 1), (2, 2), (1, 4)]:
+    mesh = make_test_mesh(shape)
+    with jax.set_mesh(mesh):
+        np.testing.assert_array_equal(np.asarray(run(lg, lse, lab, s)), one)
+        placed = jax.device_put(params, rules.named(
+            mesh, rules.safe_param_specs(params, mesh)))
+        gm = jax.jit(grad)(placed)
+    for a, b in zip(jax.tree.leaves(gm), jax.tree.leaves(g1)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= 1e-4 * np.linalg.norm(b), shape
+print("OK")
+"""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(repo, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "OK" in proc.stdout

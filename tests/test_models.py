@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config
 from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.xent_grad import ops as xent_ops
 from repro.models import attention as attn
 from repro.models import model as M
 from repro.models.attention import blockwise_attention
@@ -243,3 +244,148 @@ def test_train_loss_and_grad_equal_with_and_without_the_kernel(monkeypatch):
     for gk, gb in zip(jax.tree.leaves(grads_k), jax.tree.leaves(grads_b)):
         gk, gb = np.asarray(gk), np.asarray(gb)
         assert np.linalg.norm(gk - gb) <= 1e-5 * np.linalg.norm(gb)
+
+
+# --- the head's cross-entropy backward (xent_grad) ---------------------------
+
+def _parent_train_loss(params, batch, cfg, per_example=False):
+    """``train_loss`` as it was before the head took the kernel: autodiff
+    of the head's float32 logits and their cross entropy."""
+    x = M.forward_hidden(params, batch["tokens"], cfg,
+                         prefix=batch.get("prefix"), src=batch.get("src"))
+    logits = M._lm_head(params, x, cfg)
+    labels = batch["labels"]
+    if batch.get("prefix") is not None:
+        logits = logits[:, batch["prefix"].shape[1]:]
+    vocab = cfg.padded_vocab()
+    if vocab != cfg.vocab_size:
+        vmask = jnp.arange(vocab) < cfg.vocab_size
+        logits = jnp.where(vmask, logits, -1e30)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    ll = picked - lse
+    mask = (labels >= 0).astype(jnp.float32)
+    loss = -(ll * mask)
+    return loss.sum(axis=-1) if per_example else loss.sum()
+
+
+XENT_CASES = {
+    # name: (arch, config overrides, per_example)
+    "per_example": ("qwen1.5-4b", {}, True),
+    "summed": ("qwen1.5-4b", {}, False),
+    "bf16_compute": ("qwen1.5-4b", {"dtype": "bfloat16"}, True),
+    "tied_head": ("qwen1.5-4b", {"tie_embeddings": True}, True),
+    "vlm_prefix": ("pixtral-12b", {}, True),
+    "padded_vocab": ("qwen1.5-4b", {"vocab_size": 500}, True),
+}
+
+
+def _xent_setup(case):
+    arch, over, per_example = XENT_CASES[case]
+    cfg = get_config(arch).smoke_variant().with_overrides(**over)
+    params = M.init_params(cfg, KEY)
+    batch = _batch(cfg)
+    batch["labels"] = batch["labels"].at[0, :3].set(-1)  # ignored rows
+    return cfg, params, batch, per_example
+
+
+def _loss_and_grads(loss_fn, params, batch, cfg, per_example):
+    def total(p):
+        out = loss_fn(p, batch, cfg, per_example=per_example)
+        # weight the sequences differently, as the coded combine does
+        return (out * jnp.arange(1, out.size + 1)).sum()
+    return jax.jit(jax.value_and_grad(total))(params)
+
+
+@pytest.mark.parametrize("case", sorted(XENT_CASES))
+def test_train_loss_with_the_xent_kernel_matches_autodiff(case, monkeypatch):
+    """The kernel path (interpret mode) against autodiff: the loss to
+    float32 rounding; each gradient leaf to one bfloat16 epsilon, the
+    rounding of the head's gradient, where the layers compute in
+    float32, and to eight where they compute in bfloat16 and round it
+    again at every layer. The kernel runs once."""
+    cfg, params, batch, per_example = _xent_setup(case)
+    calls = []
+    real = xent_ops.xent_grad
+    monkeypatch.setattr(xent_ops, "xent_grad",
+                        lambda *a, **k: calls.append(k) or real(*a, **k))
+    monkeypatch.setattr(xent_ops, "_FORCE", "pallas")
+    loss_k, grads_k = _loss_and_grads(M.train_loss, params, batch, cfg,
+                                      per_example)
+    assert calls == [{"vocab_size": cfg.vocab_size}]
+    monkeypatch.setattr(xent_ops, "_FORCE", "ref")
+    loss_r, grads_r = _loss_and_grads(M.train_loss, params, batch, cfg,
+                                      per_example)
+    assert len(calls) == 1
+    np.testing.assert_allclose(float(loss_k), float(loss_r), rtol=1e-5)
+    tol = 2.0 ** -8 * (8 if cfg.dtype == "bfloat16" else 1)
+    for gk, gr in zip(jax.tree.leaves(grads_k), jax.tree.leaves(grads_r)):
+        gk = np.asarray(gk, np.float32)
+        gr = np.asarray(gr, np.float32)
+        assert np.linalg.norm(gk - gr) <= tol * np.linalg.norm(gr)
+
+
+@pytest.mark.parametrize("case", sorted(XENT_CASES))
+def test_train_loss_without_the_kernel_is_the_parent_expression(case,
+                                                                monkeypatch):
+    """With the kernel off (the CPU default, and "ref"), ``train_loss``
+    and its gradient are bit for bit the head's autodiff as it was."""
+    cfg, params, batch, per_example = _xent_setup(case)
+    want = _loss_and_grads(_parent_train_loss, params, batch, cfg,
+                           per_example)
+    for force in (None, "ref"):
+        monkeypatch.setattr(xent_ops, "_FORCE", force)
+        got = _loss_and_grads(M.train_loss, params, batch, cfg, per_example)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("precision,takes", [
+    (None, True), ("default", True), ("float32", False), ("highest", False),
+])
+def test_xent_kernel_dispatch_follows_the_matmul_precision(precision, takes,
+                                                           monkeypatch):
+    """Under a precision above the default the head's dots are exact
+    float32, so the bfloat16 gradient is not taken: ``train_loss`` is
+    then the parent's expression bit for bit, kernel forced or not."""
+    monkeypatch.setattr(xent_ops, "_FORCE", "pallas")
+    with jax.default_matmul_precision(precision):
+        assert M.uses_xent_kernel((48, 512)) is takes
+        if not takes:
+            cfg, params, batch, per_example = _xent_setup("per_example")
+            got = _loss_and_grads(M.train_loss, params, batch, cfg,
+                                  per_example)
+            want = _loss_and_grads(_parent_train_loss, params, batch, cfg,
+                                   per_example)
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("shape,takes", [
+    ((48, 512), True), ((37, 38144), True), ((48, 500), False),
+])
+def test_xent_kernel_dispatch_on_shapes(shape, takes, monkeypatch):
+    for force, enabled in (("pallas", True), ("ref", False), (None, False)):
+        monkeypatch.setattr(xent_ops, "_FORCE", force)
+        assert M.uses_xent_kernel(shape) is (takes and enabled)
+
+
+def test_serving_paths_never_take_the_xent_kernel(monkeypatch):
+    """``forward``, ``prefill`` and ``decode_step`` keep ``_lm_head``
+    whatever the dispatch says."""
+    def refuse(*a, **k):
+        raise AssertionError("the xent kernel was called")
+
+    monkeypatch.setattr(xent_ops, "xent_grad", refuse)
+    monkeypatch.setattr(xent_ops, "_FORCE", "pallas")
+    cfg = get_config("qwen1.5-4b").smoke_variant()
+    params = M.init_params(cfg, KEY)
+    tokens = jax.random.randint(KEY, (2, 8), 0, cfg.vocab_size)
+    logits = M.forward(params, tokens, cfg)
+    np.testing.assert_array_equal(
+        np.asarray(logits), np.asarray(M._lm_head(
+            params, M.forward_hidden(params, tokens, cfg), cfg)))
+    M.prefill(params, tokens, cfg)
+    cache = M.init_decode_cache(cfg, 2, 16)
+    M.decode_step(params, tokens[:, 0], cache, cfg)
+    jax.grad(lambda p: M.forward(p, tokens, cfg).sum())(params)

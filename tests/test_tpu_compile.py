@@ -16,7 +16,7 @@ import: only one process may load the TPU library at a time, and
 test workers import every test file.
 """
 
-import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +28,10 @@ from repro.kernels.decode_attention import kernel as da_k
 from repro.kernels.flash_attention import kernel as fa_k
 from repro.kernels.rmsnorm import kernel as rn_k
 from repro.kernels.spectral_matvec import kernel as sm_k
+from repro.kernels.xent_grad import kernel as xg_k
 
 D_MODEL, D_FF, N_MACHINES = 2560, 6912, 12
+TOKENS, VOCAB, PADDED_VOCAB = 12 * 1024, 37984, 38144  # the training cell
 LEAF = D_MODEL * D_FF  # one MLP weight, flattened
 
 
@@ -92,6 +94,12 @@ KERNELS = {
     # heads of 128, in the model's (B, S, H, Dh) layout.
     "flash_attention": lambda sh: _compile(
         _flash_fwd_bwd, sh, *[((12, 1024, 20, 128), "bfloat16")] * 4),
+    # The head's softmax gradient over the training cell's tokens and
+    # padded vocabulary, float32 logits in, bfloat16 out.
+    "xent_grad": lambda sh: _compile(
+        xg_k.xent_grad, sh, ((TOKENS, PADDED_VOCAB), "float32"),
+        ((TOKENS,), "float32"), ((TOKENS,), "int32"), ((TOKENS,), "float32"),
+        ((), "int32"), vocab_size=VOCAB),
     "batched_alpha": lambda sh: _compile(
         ba_k.fused_error, sh, ((1000, 2184), "float32"), ((), "float32")),
 }
@@ -100,3 +108,76 @@ KERNELS = {
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(name, one_chip):
     KERNELS[name](one_chip)
+
+
+def _computations(hlo: str) -> dict:
+    """Name -> body text of every computation in an HLO module's text."""
+    comps, name, body = {}, None, []
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name, body = head.group(1), []
+        elif line == "}" and name:
+            comps[name], name = "\n".join(body), None
+        elif name:
+            body.append(line)
+    return comps
+
+
+def _head_fusions_with_exp(hlo: str) -> list:
+    """The fusions that hold a ``model.head`` matmul (a convolution) and,
+    in themselves or a computation they call, an exponential."""
+    comps = _computations(hlo)
+
+    def reach(c, seen):
+        for callee in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)",
+                                 comps.get(c, "")):
+            if callee not in seen:
+                seen.add(callee)
+                reach(callee, seen)
+        return seen
+
+    return [c for c, body in comps.items() if c.startswith("fused")
+            and re.search(r"convolution\(.*op_name=\"[^\"]*model\.head",
+                          body)
+            and any(re.search(r"\bexponential\(", comps.get(r, ""))
+                    for r in reach(c, {c}))]
+
+
+def test_train_step_computes_the_softmax_gradient_once(one_chip,
+                                                       monkeypatch):
+    """The training cell's dedup step at its widths (one layer), built as
+    on the chip: the softmax gradient is one ``xent_grad`` call, and no
+    fusion of the head's matmuls recomputes it (no exponential)."""
+    from repro.configs import get_config
+    from repro.dist import coded_train
+    from repro.models import model as M
+    from repro.optim import optimizers as opt_mod
+
+    cfg = get_config("qwen1.5-4b").with_overrides(
+        n_layers=1, vocab_size=VOCAB, dtype="bfloat16", param_dtype="float32")
+    assert cfg.padded_vocab() == PADDED_VOCAB
+    opt = opt_mod.adamw(3e-4, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.0)
+    step = coded_train.make_train_step(cfg, opt, dedup=True, norm_scale=2.0)
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    state = jax.eval_shape(opt.init, params)
+    blocks = {k: jax.ShapeDtypeStruct((N_MACHINES, 1, 1024), jnp.int32)
+              for k in ("tokens", "labels")}
+    w = jax.ShapeDtypeStruct((N_MACHINES,), jnp.float32)
+    # Code that asks for the backend sees the CPU here; the kernels are
+    # taken, and compiled, as on the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = jax.jit(step, donate_argnums=(0, 1)).lower(
+        *placed((params, state, blocks, w))).compile().as_text()
+    calls = re.findall(r"^\s*%xent_grad[\w.]* = (\w+\[[\d,]*\])\S* "
+                       r"custom-call\(", hlo, re.M)
+    assert calls == [f"bf16[{TOKENS},{PADDED_VOCAB}]"]
+    assert _head_fusions_with_exp(hlo) == []
+    assert any("model.head" in body and "convolution(" in body
+               for body in _computations(hlo).values())
